@@ -45,6 +45,8 @@ WEIGHTS = {
     "test_precision.py": 6,
     "test_tiling_sharding.py": 6,
     "test_scheduling.py": 4,
+    "test_entry_points.py": 25,
+    "test_chip_compile.py": 10,
 }
 DEFAULT_WEIGHT = 45
 

@@ -286,13 +286,13 @@ def lint_dispatch(cfg: ModelConfig, td: TracedDispatch) -> list[Finding]:
             f"Python scalar reached the trace and will retrigger "
             f"compilation per distinct value"))
     # ...and its constant half: a large array baked into the trace
-    big_consts = [c for c in td.closed.consts
-                  if getattr(c, "nbytes", 0) > 1 << 20]
+    big_consts = [_leaf_bytes(c) for c in td.closed.consts
+                  if _leaf_bytes(c) > 1 << 20]
     if big_consts:
         out.append(Finding(
             "jaxpr", "baked-constant", subject,
             f"{len(big_consts)} closed-over array constant(s) > 1 MiB "
-            f"(largest {max(c.nbytes for c in big_consts)} B) baked "
+            f"(largest {max(big_consts)} B) baked "
             f"into the program instead of passed as arguments"))
 
     # oversized intermediates, relative to the dispatch's own io

@@ -45,5 +45,22 @@ scheduled path the fast path end to end:
     Pallas runs in interpret mode).
 
 Kernels target TPU (BlockSpec VMEM tiling, MXU-aligned blocks) and are
-validated on CPU with interpret=True.
+validated on CPU with interpret=True.  Every kernel's ``interpret``
+defaults to ``None``, which :func:`resolve_interpret` turns into compiled
+Pallas on a TPU and interpret mode anywhere else.
 """
+
+import jax
+
+
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU — the one platform check
+    behind every kernel default and the paged-decode kernel/fallback
+    choice."""
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A kernel's ``interpret`` argument: the caller's explicit choice, else
+    interpret mode exactly when no TPU is present."""
+    return (not on_tpu()) if interpret is None else interpret

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
+from repro.kernels import resolve_interpret
 
 
 def _limb_gemm_kernel(a_ref, b_ref, out_ref, acc_ref, *, gk: int):
@@ -60,7 +60,7 @@ def _limb_gemm_kernel(a_ref, b_ref, out_ref, acc_ref, *, gk: int):
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def limb_gemm_diagonals(a_limbs: jax.Array, b_limbs: jax.Array, *,
                         bm: int = 128, bn: int = 128, bk: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool | None = None) -> jax.Array:
     """Anti-diagonal partial sums of the limb GEMM.
 
     a_limbs: (la, M, K) int8 — balanced digits of A (see ref.py)
@@ -69,6 +69,7 @@ def limb_gemm_diagonals(a_limbs: jax.Array, b_limbs: jax.Array, *,
 
     M, N, K must be multiples of (bm, bn, bk) — ``ops.limb_matmul`` pads.
     """
+    interpret = resolve_interpret(interpret)
     la, M, K = a_limbs.shape
     lb, K2, N = b_limbs.shape
     if K != K2:
@@ -89,7 +90,7 @@ def limb_gemm_diagonals(a_limbs: jax.Array, b_limbs: jax.Array, *,
         out_specs=pl.BlockSpec((n_diag, bm, bn), lambda m, n, k: (0, m, n)),
         out_shape=jax.ShapeDtypeStruct((n_diag, M, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((n_diag, bm, bn), jnp.int32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="limb_gemm",

@@ -57,9 +57,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
-
 from repro.core.dataflow import Dataflow
+from repro.kernels import resolve_interpret
 
 EPILOGUES = ("fused", "spill")
 
@@ -182,7 +181,7 @@ def _os_fold_spill_kernel(a_ref, b_ref, out_ref, acc_ref, *, gkf: int):
                                              "interpret", "epilogue"))
 def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
            bm: int = 128, bn: int = 128, bk: int = 128, k_fold: int = 1,
-           out_dtype=jnp.float32, interpret: bool = True,
+           out_dtype=jnp.float32, interpret: bool | None = None,
            epilogue: str = "fused") -> jax.Array:
     """GEMM with an explicit systolic-dataflow schedule.
 
@@ -193,6 +192,7 @@ def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
     intermediate tensor exists; ``"spill"`` keeps the seed's
     materialize-then-``jnp.sum`` baseline for benchmarking.
     """
+    interpret = resolve_interpret(interpret)
     M, K = a.shape
     K2, N = b.shape
     if K != K2:
@@ -220,7 +220,7 @@ def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
                                        lambda m, n, fi, k: (fi, m, n)),
                 out_shape=jax.ShapeDtypeStruct((f, M, N), jnp.float32),
                 scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-                compiler_params=TPUCompilerParams(
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel", "parallel", "arbitrary",
                                          "arbitrary")),
                 interpret=interpret,
@@ -242,7 +242,7 @@ def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
                                        lambda m, n, fi, k: (m, n)),
                 out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
                 scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-                compiler_params=TPUCompilerParams(
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel", "parallel", "arbitrary",
                                          "arbitrary")),
                 interpret=interpret,
@@ -259,7 +259,7 @@ def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
             out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
             out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
             name="mpgemm_os",
@@ -283,7 +283,7 @@ def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
                 out_specs=pl.BlockSpec((1, bm, bn),
                                        lambda n, k, m: (k, m, n)),
                 out_shape=jax.ShapeDtypeStruct((gk, M, N), jnp.float32),
-                compiler_params=TPUCompilerParams(
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel", "arbitrary",
                                          "arbitrary")),
                 interpret=interpret,
@@ -301,7 +301,7 @@ def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
                 out_specs=pl.BlockSpec((1, bm, bn),
                                        lambda m, k, n: (k, m, n)),
                 out_shape=jax.ShapeDtypeStruct((gk, M, N), jnp.float32),
-                compiler_params=TPUCompilerParams(
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel", "arbitrary",
                                          "arbitrary")),
                 interpret=interpret,
@@ -323,7 +323,7 @@ def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda n, fi, k, m: (m, n)),
             out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-            compiler_params=TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                      "arbitrary")),
             interpret=interpret,
@@ -342,7 +342,7 @@ def mpgemm(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda m, fi, k, n: (m, n)),
             out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-            compiler_params=TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                      "arbitrary")),
             interpret=interpret,

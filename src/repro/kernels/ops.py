@@ -51,10 +51,6 @@ from repro.kernels import quant_matmul as _qm
 from repro.kernels.ref import LIMB_BITS, n_limbs_for
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _pad2(x: jax.Array, m0: int, m1: int) -> jax.Array:
     p0 = (-x.shape[-2]) % m0
     p1 = (-x.shape[-1]) % m1
@@ -103,7 +99,6 @@ def limb_matmul(a: jax.Array, b: jax.Array, *,
         raise ValueError("mixed input dtypes need explicit in_bits")
     bits = in_bits or jnp.dtype(a.dtype).itemsize * 8
     nl = n_limbs_for(bits, LIMB_BITS)
-    interp = _interpret() if interpret is None else interpret
 
     M, K = a.shape
     _, N = b.shape
@@ -116,7 +111,7 @@ def limb_matmul(a: jax.Array, b: jax.Array, *,
     a_l = _pad2(_lg.limb_decompose(a, nl, LIMB_BITS), bm, bk)
     b_l = _pad2(_lg.limb_decompose(b, nl, LIMB_BITS), bk, bn)
     diags = _lg.limb_gemm_diagonals(a_l, b_l, bm=bm, bn=bn, bk=bk,
-                                    interpret=interp)
+                                    interpret=interpret)
     hi, lo = accumulator.combine_diagonals(diags, LIMB_BITS)
     return hi[:M, :N], lo[:M, :N]
 
@@ -155,7 +150,6 @@ def matmul(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
     ``epilogue`` selects the fused reduction (default) or the legacy
     partial-plane spill baseline (benchmarks only).
     """
-    interp = _interpret() if interpret is None else interpret
     M, K = a.shape
     _, N = b.shape
 
@@ -192,7 +186,7 @@ def matmul(a: jax.Array, b: jax.Array, *, dataflow: Dataflow = Dataflow.OS,
     ef = _mp.effective_fold(ap.shape[-1], bk, fold_req)
     out = _mp.mpgemm(ap, bp, dataflow=dataflow, bm=bm, bn=bn, bk=bk,
                      k_fold=ef, out_dtype=out_dtype, epilogue=epilogue,
-                     interpret=interp)
+                     interpret=interpret)
     if schedule is not None:
         # logged AFTER the dispatch so the applied log records only GEMMs
         # that really executed (a raising dispatch must not leave a
@@ -230,7 +224,6 @@ def quant_matmul(x: jax.Array, w_q: jax.Array, scale: jax.Array, *,
     with the per-channel dequant fused into the accumulator flush, so the
     applied dataflow is OS and the fold is 1 regardless of the modeled
     winner — the honest record of what ran)."""
-    interp = _interpret() if interpret is None else interpret
     M, K = x.shape
     _, N = w_q.shape
     if schedule is not None:
@@ -247,7 +240,7 @@ def quant_matmul(x: jax.Array, w_q: jax.Array, scale: jax.Array, *,
     wp = _pad2(w_q, bk, bn)
     sp = scale if N % bn == 0 else jnp.pad(scale, (0, (-N) % bn))
     out = _qm.quant_matmul(xp, wp, sp, bm=bm, bn=bn, bk=bk,
-                           out_dtype=out_dtype, interpret=interp)
+                           out_dtype=out_dtype, interpret=interpret)
     if out.shape == (M, N):
         return out
     return out[:M, :N]

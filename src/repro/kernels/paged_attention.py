@@ -33,9 +33,10 @@ Two implementations, one contract (``decode_attention``):
 
 ``decode_attention`` picks the kernel on TPU and the fallback elsewhere;
 ``use_kernel=True`` with ``interpret=True`` runs the kernel anywhere
-(tests).  Shapes are toy-friendly; production TPU deployment wants hd
-padded to 128 lanes (see the tiling notes in ``/opt`` guides — same
-caveat as the other kernels in this package).
+(tests).  At qwen2-0.5b widths (hd 64, G 7, KV 2, 16-token blocks: tiles
+below the (8, 128) layout) the kernel compiles for a v5e and matches the
+float32 gather reference there, bf16 and int8 KV alike (``chip_smoke.py``);
+whether padding hd to 128 lanes would be faster is not measured.
 """
 
 from __future__ import annotations
@@ -46,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import on_tpu, resolve_interpret
 
 NEG_INF = -1e30
 
@@ -124,7 +127,7 @@ def paged_decode_kernel(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         block_table: jax.Array, lengths: jax.Array, *,
                         scale: float, window: int | None = None,
                         logit_cap: float | None = None,
-                        interpret: bool = False,
+                        interpret: bool | None = None,
                         k_scale: jax.Array | None = None,
                         v_scale: jax.Array | None = None) -> jax.Array:
     """Pallas paged-decode attention.
@@ -134,6 +137,7 @@ def paged_decode_kernel(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     With ``k_scale``/``v_scale`` (nb, bs, KV) the pools are int8 and the
     dequant (payload * scale) is fused into the per-block fetch.
     """
+    interpret = resolve_interpret(interpret)
     B, KV, G, hd = q.shape
     nb, bs, _, hdv = v_pool.shape
     nbs = block_table.shape[1]
@@ -245,9 +249,8 @@ def decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     gather path elsewhere (``use_kernel``/``interpret`` override for
     tests — the kernel runs anywhere under interpret mode).  Int8 pools
     pass their scale sidecars; both paths fuse the dequant."""
-    on_tpu = jax.default_backend() == "tpu"
     if use_kernel is None:
-        use_kernel = on_tpu
+        use_kernel = on_tpu()
     if not use_kernel:
         return gather_fallback(q, k_pool, v_pool, block_table, lengths,
                                scale=scale, window=window,
@@ -255,8 +258,7 @@ def decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                k_scale=k_scale, v_scale=v_scale)
     return paged_decode_kernel(
         q, k_pool, v_pool, block_table, lengths, scale=scale, window=window,
-        logit_cap=logit_cap,
-        interpret=(not on_tpu) if interpret is None else interpret,
+        logit_cap=logit_cap, interpret=interpret,
         k_scale=k_scale, v_scale=v_scale)
 
 

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
+from repro.kernels import resolve_interpret
 
 
 def _quant_matmul_kernel(x_ref, wq_ref, scale_ref, out_ref, acc_ref, *,
@@ -44,11 +44,13 @@ def _quant_matmul_kernel(x_ref, wq_ref, scale_ref, out_ref, acc_ref, *,
                                              "interpret"))
 def quant_matmul(x: jax.Array, w_q: jax.Array, scale: jax.Array, *,
                  bm: int = 128, bn: int = 128, bk: int = 128,
-                 out_dtype=jnp.float32, interpret: bool = True) -> jax.Array:
+                 out_dtype=jnp.float32, interpret: bool | None = None
+                 ) -> jax.Array:
     """x: (M, K) bf16/f32; w_q: (K, N) int8; scale: (N,) f32 per-channel.
 
     Returns (M, N) ``out_dtype`` = (x @ w_q) * scale.
     """
+    interpret = resolve_interpret(interpret)
     M, K = x.shape
     K2, N = w_q.shape
     if K != K2 or scale.shape != (N,):
@@ -71,7 +73,7 @@ def quant_matmul(x: jax.Array, w_q: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="quant_matmul",

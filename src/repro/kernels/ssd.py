@@ -20,8 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
+from repro.kernels import resolve_interpret
 
 
 def _ssd_intra_kernel(x_ref, dt_ref, cums_ref, b_ref, c_ref, y_ref):
@@ -50,7 +51,7 @@ def _ssd_intra_kernel(x_ref, dt_ref, cums_ref, b_ref, c_ref, y_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_intra(x: jax.Array, dt: jax.Array, cums: jax.Array, b: jax.Array,
-              c: jax.Array, *, interpret: bool = True) -> jax.Array:
+              c: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Intra-chunk SSD contributions.
 
     x    (G, Q, P)  — G = batch*chunks*heads flattened grid dim
@@ -59,6 +60,7 @@ def ssd_intra(x: jax.Array, dt: jax.Array, cums: jax.Array, b: jax.Array,
     b, c (G, Q, N)  — input/output state projections (per head)
     returns y (G, Q, P) fp32.
     """
+    interpret = resolve_interpret(interpret)
     G, Q, P = x.shape
     N = b.shape[-1]
     return pl.pallas_call(
@@ -73,7 +75,7 @@ def ssd_intra(x: jax.Array, dt: jax.Array, cums: jax.Array, b: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, Q, P), lambda g: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((G, Q, P), jnp.float32),
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="ssd_intra",

@@ -1,11 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-#   512 placeholder host devices let jax.make_mesh build the production
-#   meshes (16x16 single-pod slice of the fleet, 2x16x16 multi-pod).
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 derive the roofline terms from the compiled artifact.
+
+CPU-only tool.  Importing it overwrites ``XLA_FLAGS`` (512 placeholder
+host devices) and ``--all`` starts one subprocess per cell, so never
+import it from a process that drives a chip: a TPU belongs to one process
+at a time.  The serving path and ``chip_smoke.py`` do not import it.
 
 For each cell this proves, without hardware:
   * the sharding config is coherent (no GSPMD conflicts),
@@ -20,6 +19,12 @@ Usage:
                                                    # per cell, resumable)
 Results: experiments/dryrun/<arch>__<shape>__<mesh>.json
 """
+
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# ^ MUST precede any jax import: jax locks the device count on first init.
+#   512 placeholder host devices let jax.make_mesh build the production
+#   meshes (16x16 single-pod slice of the fleet, 2x16x16 multi-pod).
 
 import argparse
 import functools

@@ -34,7 +34,7 @@ _FUSABLE = {
     "lt", "le", "gt", "ge", "stop_gradient", "erf", "erf_inv", "expm1",
     "log1p", "cos", "sin", "clamp", "shift_left", "shift_right_logical",
     "shift_right_arithmetic", "rem", "copy", "real", "imag", "is_finite",
-    "pjit", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+    "jit", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "remat2", "checkpoint", "closed_call", "cond", "while", "scan",
     "dot_general", "reduce_sum", "reduce_max", "reduce_min", "reduce_prod",
     "argmax", "argmin", "cumsum", "cumlogsumexp", "cummax", "cumprod",
@@ -125,7 +125,7 @@ def _jaxpr_cost(jaxpr) -> Cost:
             if sub is not None:
                 inner_jaxpr = sub.jaxpr if hasattr(sub, "jaxpr") else sub
                 total += _jaxpr_cost(inner_jaxpr).scaled(float(n))
-        elif prim in ("pjit", "closed_call", "remat2", "checkpoint",
+        elif prim in ("jit", "closed_call", "remat2", "checkpoint",
                       "custom_jvp_call", "custom_vjp_call",
                       "custom_vjp_call_jaxpr"):
             sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")

@@ -12,15 +12,22 @@ exist) so examples/tests run the identical code path at toy scale.
 
 from __future__ import annotations
 
-
 import jax
 import numpy as np
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules here
+    are GSPMD annotations.  (JAX's default is ``Explicit`` axes, under
+    which the embedding gather and other sharded ops refuse to trace.)"""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(model_parallel: int = 1):
@@ -28,8 +35,8 @@ def make_local_mesh(model_parallel: int = 1):
     n = jax.device_count()
     if n % model_parallel:
         raise ValueError(f"{n} devices not divisible by mp={model_parallel}")
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return _auto_mesh((n // model_parallel, model_parallel),
+                      ("data", "model"))
 
 
 def make_elastic_mesh(n_chips: int, model_parallel: int):

@@ -22,6 +22,12 @@ Speculative decoding (``--spec ngram`` / ``--spec model:<arch>``,
 dispatch (token-identical greedy output, fewer engine steps; see
 ``serving.spec``).
 
+The run fails loudly: ``main`` returns the Results, and exits nonzero
+(after printing the status counts and the first error) when any request
+ends in a status other than ``ok`` or the run emits no tokens — the
+engine's step watchdog keeps serving past a failing dispatch, so this is
+where a broken kernel surfaces.
+
 CLI (CPU demo sizes):
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
         --scaled-down --requests 8 --max-new 16 --quant
@@ -32,13 +38,16 @@ CLI (CPU demo sizes):
 from __future__ import annotations
 
 import argparse
+import collections
 import time
+import traceback
 
 import jax
 import numpy as np
 
 from repro import configs as CONFIGS
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import network as N
 from repro.obs import Telemetry, render_report
 from repro.quant.policy import quantize_params
@@ -51,7 +60,26 @@ def _percentile(xs: list[float], q: float) -> float:
     return float(np.percentile(np.asarray(xs), q)) if xs else 0.0
 
 
-def main(argv=None):
+def make_requests(rng: np.random.Generator, n: int, prompt_len: int,
+                  max_new: int, vocab: int, temperature: float = 0.0,
+                  ttft_slo: float | None = None) -> list[Request]:
+    """The CLI's synthetic trace: prompts of ``prompt_len // 2 ..
+    prompt_len`` random tokens, each asking ``max_new // 2 .. max_new`` new
+    tokens.  ``main`` draws it from ``np.random.default_rng(0)``."""
+    return [Request(rid=i,
+                    prompt=rng.integers(
+                        3, vocab,
+                        max(1, int(rng.integers(
+                            prompt_len // 2, prompt_len + 1)))).astype(
+                                np.int32),
+                    max_new_tokens=max(1, int(rng.integers(
+                        max_new // 2, max_new + 1))),
+                    temperature=temperature,
+                    ttft_slo=ttft_slo)
+            for i in range(n)]
+
+
+def main(argv=None) -> list[Result]:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--scaled-down", action="store_true")
@@ -120,6 +148,7 @@ def main(argv=None):
                          "and modeled-cost cross-checks (see "
                          "scripts/trace_report.py); implies tracing")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     import dataclasses
 
@@ -161,17 +190,8 @@ def main(argv=None):
         print(f"[serve] int8 serving path: QuantTensor weights + {kv}")
 
     rng = np.random.default_rng(0)
-    reqs = [Request(rid=i,
-                    prompt=rng.integers(
-                        3, cfg.vocab,
-                        max(1, int(rng.integers(
-                            args.prompt_len // 2,
-                            args.prompt_len + 1)))).astype(np.int32),
-                    max_new_tokens=max(1, int(rng.integers(
-                        args.max_new // 2, args.max_new + 1))),
-                    temperature=args.temperature,
-                    ttft_slo=args.ttft_slo or None)
-            for i in range(args.requests)]
+    reqs = make_requests(rng, args.requests, args.prompt_len, args.max_new,
+                         cfg.vocab, args.temperature, args.ttft_slo or None)
 
     spec = None
     if args.spec:
@@ -277,6 +297,20 @@ def main(argv=None):
         if args.metrics_out:
             eng.obs.export_metrics(args.metrics_out)
             print(f"[serve] metrics -> {args.metrics_out}")
+
+    status = collections.Counter(r.status for r in results)
+    print("[serve] request status: "
+          + ", ".join(f"{k}={v}" for k, v in sorted(status.items())))
+    if toks == 0 or set(status) != {"ok"}:
+        first = next((r.error for r in results if r.error), None)
+        print(f"[serve] FAILED: {toks} tokens emitted; first classified "
+              f"error: {first}")
+        exc = getattr(eng, "last_dispatch_error", None)
+        if exc is not None:
+            print("[serve] last dispatch error:\n"
+                  + "".join(traceback.format_exception(exc)))
+        raise SystemExit(1)
+    return results
 
 
 if __name__ == "__main__":

@@ -28,6 +28,7 @@ from repro import configs as CONFIGS
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import DataConfig, make_batch
 from repro.launch import sharding as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, mesh_chips
 from repro.models import network as N
 from repro.models.config import ModelConfig
@@ -113,8 +114,6 @@ def make_compressed_dp_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig,
     batch sharded over 'data'.  step(params, opt, err, key, batch) -> ..."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     def loss(p, b):
         return N.loss_fn(p, cfg, b)
 
@@ -135,7 +134,7 @@ def make_compressed_dp_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig,
 
     rep = P()
     bspec = jax.tree.map(lambda _: P("data"), {"tokens": 0, "labels": 0})
-    smapped = shard_map(
+    smapped = jax.shard_map(
         dp_step, mesh=mesh,
         in_specs=(rep, rep, rep, rep, bspec),
         out_specs=(rep, rep, rep, rep),
@@ -263,6 +262,7 @@ def main(argv=None):
                     help="override width (with --scaled-down)")
     ap.add_argument("--n-layers", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = CONFIGS.get(args.arch)
     if args.scaled_down:

@@ -13,11 +13,9 @@ utilization-vs-drop tradeoff and the aux loss keeps the router balanced.
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models.config import ModelConfig, MoEConfig
 from repro.models.layers import ParamDef, activation, dense, shard_act
 
@@ -281,8 +279,8 @@ def _moe_shardmap(p: dict, x: jax.Array, cfg: ModelConfig, mesh, dp,
 
     p_specs = jax.tree_util.tree_map_with_path(leaf_spec, p)
 
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(p_specs, P(dspec, None, None)),
-                   out_specs=(P(dspec, None, None), P()),
-                   axis_names=set(dp) | {"model"}, check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(p_specs, P(dspec, None, None)),
+                       out_specs=(P(dspec, None, None), P()),
+                       axis_names=set(dp) | {"model"}, check_vma=False)
     return fn(p, x)
