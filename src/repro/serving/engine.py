@@ -133,6 +133,7 @@ from repro.kernels import paged_attention as PA
 from repro.models import network as N
 from repro.models.config import ModelConfig
 from repro.obs import Telemetry
+from repro.obs.events import NO_SPAN
 from repro.quant import QuantPolicy, choose_precision, serving_quant_params
 from repro.serving.kv_pool import KVPool, PoolAuditError, blocks_for
 from repro.serving.policy import (PendingView, SchedulerPolicy, SlotView,
@@ -976,12 +977,16 @@ class ContinuousEngine:
         guarantee unchanged."""
         req = ent.req
         remaining_new = req.max_new_tokens - len(ent.resume_tokens)
-        plan = self.pool.admit(slot, [int(t) for t in ent.full_prompt],
-                               self._reserve_horizon(remaining_new))
+        tr = self._tr
+        with (tr.span("pool_admit", rid=req.rid, slot=slot) if tr.enabled
+              else NO_SPAN):
+            plan = self.pool.admit(slot, [int(t) for t in ent.full_prompt],
+                                   self._reserve_horizon(remaining_new))
+            if plan is not None:
+                t0 = time.perf_counter()
+                self._bt = jnp.asarray(self.pool.tables)
         if plan is None:
             return False
-        t0 = time.perf_counter()
-        self._bt = jnp.asarray(self.pool.tables)
         # fresh recurrent state + cursor at the resident prefix length
         self.caches = self._fns["reset_slot"](
             self.caches, jnp.asarray(slot, jnp.int32),
@@ -1014,6 +1019,16 @@ class ContinuousEngine:
         return True
 
     def _admit(self) -> None:
+        """Admit what the policy picks while a slot is free; one
+        ``admit_pass`` span when there is a queue to pick from."""
+        tr = self._tr
+        if tr.enabled and self._pending and None in self._slots:
+            with tr.span("admit_pass"):
+                self._admit_loop()
+        else:
+            self._admit_loop()
+
+    def _admit_loop(self) -> None:
         while True:
             slot = self._free_slot()
             if slot is None:
@@ -1120,8 +1135,11 @@ class ContinuousEngine:
         # registration covers only FULL blocks among the resident
         # positions [0, pos) — the tail (incl. the not-yet-written last
         # produced token) is re-prefilled on resume.
-        self.pool.release_slot(slot, prompt=full_seq)
-        self._bt = jnp.asarray(self.pool.tables)
+        tr = self._tr
+        with (tr.span("pool_release", rid=st.req.rid, slot=slot)
+              if tr.enabled else NO_SPAN):
+            self.pool.release_slot(slot, prompt=full_seq)
+            self._bt = jnp.asarray(self.pool.tables)
         self._slots[slot] = None
         self._c_preemptions.inc()
         if self._tr.enabled:
@@ -1157,20 +1175,24 @@ class ContinuousEngine:
         self._h_ttft_steps.observe(max(st.ttft_steps, 0))
         self._h_ttft_s.observe(st.t_first - st.t_submit)
         self._h_latency.observe(now - st.t_submit)
-        if self._tr.enabled:
-            self._tr.event("finish", rid=st.req.rid, slot=slot,
-                           step=self.steps + self.chunk_steps, ts=now,
-                           tokens=len(st.produced),
-                           preemptions=st.preemptions)
         self._slots[slot] = None
+        tr = self._tr
         if self.paged:
             # release refs; full prompt blocks (of the ADMISSION prompt —
             # original prompt + any resume tail) stay content-addressed
             # in the prefix cache until evicted, so an identical prompt
             # later skips their prefill entirely.
-            self.pool.release_slot(slot, prompt=[int(t)
-                                                 for t in st.full_prompt])
-            self._bt = jnp.asarray(self.pool.tables)
+            with (tr.span("pool_release", rid=st.req.rid, slot=slot)
+                  if tr.enabled else NO_SPAN):
+                self.pool.release_slot(
+                    slot, prompt=[int(t) for t in st.full_prompt])
+                self._bt = jnp.asarray(self.pool.tables)
+        if tr.enabled:
+            # recorded after the release, so that a request's own events
+            # end with its finish
+            tr.event("finish", rid=st.req.rid, slot=slot,
+                     step=self.steps + self.chunk_steps, ts=now,
+                     tokens=len(st.produced), preemptions=st.preemptions)
 
     # -- resilience: lifecycle guards, step watchdog, warm restart ----------
     # (docs/RELIABILITY.md)
@@ -1232,19 +1254,22 @@ class ContinuousEngine:
         self._c_finished.inc()
         self._c_tokens.inc(len(st.produced))
         self._h_latency.observe(now - st.t_submit)
-        if self._tr.enabled:
-            self._tr.event("finish", rid=st.req.rid, slot=slot,
-                           step=self.steps + self.chunk_steps, ts=now,
-                           tokens=len(st.produced), status=status)
         self._slots[slot] = None
+        tr = self._tr
         if self.paged:
-            if st.phase == "decode" and st.produced:
-                full_seq = ([int(t) for t in st.req.prompt]
-                            + [int(t) for t in st.produced])
-                self.pool.release_slot(slot, prompt=full_seq)
-            else:
-                self.pool.release_slot(slot)
-            self._bt = jnp.asarray(self.pool.tables)
+            with (tr.span("pool_release", rid=st.req.rid, slot=slot)
+                  if tr.enabled else NO_SPAN):
+                if st.phase == "decode" and st.produced:
+                    full_seq = ([int(t) for t in st.req.prompt]
+                                + [int(t) for t in st.produced])
+                    self.pool.release_slot(slot, prompt=full_seq)
+                else:
+                    self.pool.release_slot(slot)
+                self._bt = jnp.asarray(self.pool.tables)
+        if tr.enabled:
+            tr.event("finish", rid=st.req.rid, slot=slot,
+                     step=self.steps + self.chunk_steps, ts=now,
+                     tokens=len(st.produced), status=status)
 
     def _service_guards(self) -> None:
         """Start-of-step lifecycle sweep: cancellations first, then hard
@@ -1539,83 +1564,95 @@ class ContinuousEngine:
         admission): a single jitted call advances them all; rows not mid-
         prefill ride along masked (len 0 — recurrent state untouched,
         stray writes land beyond their validity bound or in the trash
-        block)."""
-        L = self.prefill_chunk
-        toks = np.zeros((self.slots, L), np.int32)
-        lens = np.zeros(self.slots, np.int32)
-        temps = np.zeros(self.slots, np.float32)
-        for i in pre:
-            st = self._slots[i]
-            # peek, don't pop: the chunk is consumed only after the
-            # dispatch returns, so a watchdog-retried step re-runs it
-            chunk = st.chunks[0]
-            toks[i, :len(chunk)] = chunk
-            lens[i] = len(chunk)
-            temps[i] = st.req.temperature
-            self.pool.ensure_writable(i, int(self._pos[i]),
-                                      int(self._pos[i]) + L - 1)
-        self._apply_cow()
-        self._register_gemms(self.slots * L, self.slots)
+        block).  Traced, the ``chunk_batch`` span holds the dispatch's
+        ``prep``/``launch``/``sync``; ``retire`` follows it."""
+        tr = self._tr
+        # the step index the chunk_batch event carries once it has run
+        with (tr.span("chunk_batch", step=self.steps + self.chunk_steps + 1,
+                      rows=len(pre)) if tr.enabled else NO_SPAN):
+            with (tr.span("prep") if tr.enabled else NO_SPAN):
+                L = self.prefill_chunk
+                toks = np.zeros((self.slots, L), np.int32)
+                lens = np.zeros(self.slots, np.int32)
+                temps = np.zeros(self.slots, np.float32)
+                for i in pre:
+                    st = self._slots[i]
+                    # peek, don't pop: the chunk is consumed only after
+                    # the dispatch returns, so a watchdog-retried step
+                    # re-runs it
+                    chunk = st.chunks[0]
+                    toks[i, :len(chunk)] = chunk
+                    lens[i] = len(chunk)
+                    temps[i] = st.req.temperature
+                    self.pool.ensure_writable(i, int(self._pos[i]),
+                                              int(self._pos[i]) + L - 1)
+                self._apply_cow()
+                self._register_gemms(self.slots * L, self.slots)
+                last_idx = np.maximum(lens - 1, 0)
 
-        t0 = time.perf_counter()
-        last_idx = np.maximum(lens - 1, 0)
-        tok, self.caches, self.key = self._fns["prefill_chunk"](
-            self.params, jnp.asarray(toks), self.caches, self._slot_ids,
-            self._bt, jnp.asarray(lens),
-            jnp.asarray(last_idx), self.key,
-            jnp.asarray(temps))
-        if self.spec is not None:
-            # the draft model prefills the SAME chunk through the same
-            # tables, so its KV stays position-for-position resident with
-            # the target's (shared prefixes included — both models wrote
-            # the cached blocks when they were first prefilled).
-            self.spec.on_prefill_chunk(self, toks, lens, last_idx)
-        self._c_chunk_steps.inc()
-        if any(s is not None and s.phase == "decode" for s in self._slots):
-            self._chunks_since_decode += 1
-            self.max_chunk_gap = max(self.max_chunk_gap,
-                                     self._chunks_since_decode)
-        tok_np = np.asarray(tok)
-        now = time.perf_counter()
-        self._s_chunk.append(now - t0)
-        if self._tr.enabled:
-            step = self.steps + self.chunk_steps
-            self._tr.event("chunk_batch", step=step, ts=t0, dur=now - t0,
-                           rows=len(pre))
+            t0 = time.perf_counter()
+            with (tr.span("launch") if tr.enabled else NO_SPAN):
+                tok, self.caches, self.key = self._fns["prefill_chunk"](
+                    self.params, jnp.asarray(toks), self.caches,
+                    self._slot_ids, self._bt, jnp.asarray(lens),
+                    jnp.asarray(last_idx), self.key,
+                    jnp.asarray(temps))
+                if self.spec is not None:
+                    # the draft model prefills the SAME chunk through the
+                    # same tables, so its KV stays position-for-position
+                    # resident with the target's (shared prefixes
+                    # included — both models wrote the cached blocks when
+                    # they were first prefilled).
+                    self.spec.on_prefill_chunk(self, toks, lens, last_idx)
+            self._c_chunk_steps.inc()
+            if any(s is not None and s.phase == "decode"
+                   for s in self._slots):
+                self._chunks_since_decode += 1
+                self.max_chunk_gap = max(self.max_chunk_gap,
+                                         self._chunks_since_decode)
+            with (tr.span("sync") if tr.enabled else NO_SPAN):
+                tok_np = np.asarray(tok)
+            now = time.perf_counter()
+            self._s_chunk.append(now - t0)
+            if tr.enabled:
+                step = self.steps + self.chunk_steps
+                for i in pre:
+                    tr.event("prefill_chunk", rid=self._slots[i].req.rid,
+                             slot=i, step=step, ts=t0, dur=now - t0,
+                             tokens=int(lens[i]))
+        with (tr.span("retire") if tr.enabled else NO_SPAN):
             for i in pre:
-                self._tr.event("prefill_chunk", rid=self._slots[i].req.rid,
-                               slot=i, step=step, ts=t0, dur=now - t0,
-                               tokens=int(lens[i]))
-        for i in pre:
-            st = self._slots[i]
-            st.chunks.pop(0)
-            self._pos[i] += int(lens[i])
-            if st.chunks:
-                continue                       # more chunks next step
-            self._c_prefills.inc()
-            st.phase = "decode"
-            st.t_prefill_done = now
-            if st.t_first == 0.0:              # resumed slots keep theirs
-                st.t_first = now
-            if st.ttft_steps < 0:
-                st.ttft_steps = self.steps + self.chunk_steps
-                if self._tr.enabled:
-                    self._tr.event("first_token", rid=st.req.rid, slot=i,
-                                   step=st.ttft_steps, ts=now)
-            # prompt KV is now fully resident: content-address its full
-            # blocks so even a CONCURRENT identical prompt shares them
-            # (release re-registers, which is a no-op).
-            n = int(self.pool.n_slot_blocks[i])
-            self.pool.register_prefix(
-                [int(t) for t in st.full_prompt],
-                [int(b) for b in self.pool.tables[i, :n]])
-            tok0 = int(tok_np[i])
-            st.produced.append(tok0)
-            st.cur_tok = tok0
-            if (tok0 == st.req.eos
-                    or len(st.produced) >= st.req.max_new_tokens
-                    or self._pos[i] >= self.max_len):
-                self._finish(i)
+                st = self._slots[i]
+                st.chunks.pop(0)
+                self._pos[i] += int(lens[i])
+                if st.chunks:
+                    continue                   # more chunks next step
+                self._c_prefills.inc()
+                st.phase = "decode"
+                st.t_prefill_done = now
+                if st.t_first == 0.0:          # resumed slots keep theirs
+                    st.t_first = now
+                if st.ttft_steps < 0:
+                    st.ttft_steps = self.steps + self.chunk_steps
+                    if tr.enabled:
+                        tr.event("first_token", rid=st.req.rid, slot=i,
+                                 step=st.ttft_steps, ts=now)
+                # prompt KV is now fully resident: content-address its
+                # full blocks so even a CONCURRENT identical prompt shares
+                # them (release re-registers, which is a no-op).
+                with (tr.span("pool_register", rid=st.req.rid, slot=i)
+                      if tr.enabled else NO_SPAN):
+                    n = int(self.pool.n_slot_blocks[i])
+                    self.pool.register_prefix(
+                        [int(t) for t in st.full_prompt],
+                        [int(b) for b in self.pool.tables[i, :n]])
+                tok0 = int(tok_np[i])
+                st.produced.append(tok0)
+                st.cur_tok = tok0
+                if (tok0 == st.req.eos
+                        or len(st.produced) >= st.req.max_new_tokens
+                        or self._pos[i] >= self.max_len):
+                    self._finish(i)
 
     def _end_step(self) -> int:
         """Common step epilogue: pool-utilization sample + optional
@@ -1667,8 +1704,21 @@ class ContinuousEngine:
         (``_dispatch_guarded``): a failing dispatch never kills the
         engine — it is retried next step or its requests are
         quarantined — except :class:`EngineCrash` (warm-restart drill)
-        and :class:`PoolAuditError`, which always propagate."""
+        and :class:`PoolAuditError`, which always propagate.
+
+        Traced, a call that starts with a request queued or in a slot is
+        one ``step`` span (the root of the spans inside it); idle calls
+        record nothing."""
         self._ticks += 1
+        tr = self._tr
+        if tr.enabled:
+            tr.tick = self._ticks
+            if self._pending or any(s is not None for s in self._slots):
+                with tr.span("step"):
+                    return self._step()
+        return self._step()
+
+    def _step(self) -> int:
         if self._cancels or self._deadlines:
             self._service_guards()
         self._admit()
@@ -1691,55 +1741,71 @@ class ContinuousEngine:
         return self._end_step()
 
     def _decode_step(self, active: list[int]) -> None:
-        """ONE batched single-token decode dispatch over ``active``."""
-        t0 = time.perf_counter()
-        self._register_gemms(self.slots, self.slots)
-        toks = np.zeros((self.slots, 1), np.int32)
-        temps = np.zeros(self.slots, np.float32)
-        adv = np.zeros(self.slots, np.int32)
-        for i in active:
-            toks[i, 0] = self._slots[i].cur_tok
-            temps[i] = self._slots[i].req.temperature
-            adv[i] = 1
+        """ONE batched single-token decode dispatch over ``active``.
+        Traced, the ``decode`` span (its rows' request ids and attended
+        lengths as ``rids``/``ctx``) holds ``prep``/``launch``/``sync``;
+        its end is when the step's tokens reach the host.  ``retire``
+        follows it."""
+        tr = self._tr
+        # the step index the decode event carries once it has run
+        with (tr.span("decode", step=self.steps + self.chunk_steps + 1,
+                      rows=len(active),
+                      rids=[self._slots[i].req.rid for i in active],
+                      ctx=[int(self._pos[i]) + 1 for i in active])
+              if tr.enabled else NO_SPAN):
+            with (tr.span("prep") if tr.enabled else NO_SPAN):
+                self._register_gemms(self.slots, self.slots)
+                toks = np.zeros((self.slots, 1), np.int32)
+                temps = np.zeros(self.slots, np.float32)
+                adv = np.zeros(self.slots, np.int32)
+                for i in active:
+                    toks[i, 0] = self._slots[i].cur_tok
+                    temps[i] = self._slots[i].req.temperature
+                    adv[i] = 1
+                if self.paged:
+                    for i in active:
+                        self.pool.ensure_writable(i, int(self._pos[i]),
+                                                  int(self._pos[i]))
+                    self._apply_cow()
 
-        if self.paged:
+            if self.paged:
+                with (tr.span("launch") if tr.enabled else NO_SPAN):
+                    tok, self.caches, self.key = self._fns[
+                        "decode_sample_paged"](
+                        self.params, jnp.asarray(toks), self.caches,
+                        jnp.asarray(self._pos), self._bt, jnp.asarray(adv),
+                        self.key, jnp.asarray(temps))
+                # the dispatch above IS the application of the gather
+                # GEMMs; record it now so the applied log mirrors real
+                # decode steps.
+                PA.note_gather_applied(self.schedule, self.cfg,
+                                       self.pool.block_size, self._prec)
+                self._pos += adv        # only decoding slots advanced
+                self._chunks_since_decode = 0
+            else:
+                with (tr.span("launch") if tr.enabled else NO_SPAN):
+                    tok, self.caches, self.key = self._fns["decode_sample"](
+                        self.params, jnp.asarray(toks), self.caches,
+                        jnp.asarray(self._pos), self.key,
+                        jnp.asarray(temps))
+                # every slot's cache pos advanced by 1 (inactive slots
+                # write masked garbage in place); mirror it so the next
+                # step agrees.
+                self._pos += 1
+            self._c_steps.inc()
+            self._s_decode.append(time.perf_counter())
+
+            with (tr.span("sync") if tr.enabled else NO_SPAN):
+                tok_np = np.asarray(tok)
+        with (tr.span("retire") if tr.enabled else NO_SPAN):
             for i in active:
-                self.pool.ensure_writable(i, int(self._pos[i]),
-                                          int(self._pos[i]))
-            self._apply_cow()
-            tok, self.caches, self.key = self._fns["decode_sample_paged"](
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(self._pos), self._bt, jnp.asarray(adv),
-                self.key, jnp.asarray(temps))
-            # the dispatch above IS the application of the gather GEMMs;
-            # record it now so the applied log mirrors real decode steps.
-            PA.note_gather_applied(self.schedule, self.cfg,
-                                   self.pool.block_size, self._prec)
-            self._pos += adv        # only decoding slots advanced
-            self._chunks_since_decode = 0
-        else:
-            tok, self.caches, self.key = self._fns["decode_sample"](
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(self._pos), self.key, jnp.asarray(temps))
-            # every slot's cache pos advanced by 1 (inactive slots write
-            # masked garbage in place); mirror it so the next step agrees.
-            self._pos += 1
-        self._c_steps.inc()
-        self._s_decode.append(time.perf_counter())
-
-        tok_np = np.asarray(tok)
-        if self._tr.enabled:
-            self._tr.event("decode", step=self.steps + self.chunk_steps,
-                           ts=t0, dur=time.perf_counter() - t0,
-                           rows=len(active))
-        for i in active:
-            st = self._slots[i]
-            st.produced.append(int(tok_np[i]))
-            st.cur_tok = int(tok_np[i])
-            if (st.cur_tok == st.req.eos
-                    or len(st.produced) >= st.req.max_new_tokens
-                    or self._pos[i] >= self.max_len):
-                self._finish(i)
+                st = self._slots[i]
+                st.produced.append(int(tok_np[i]))
+                st.cur_tok = int(tok_np[i])
+                if (st.cur_tok == st.req.eos
+                        or len(st.produced) >= st.req.max_new_tokens
+                        or self._pos[i] >= self.max_len):
+                    self._finish(i)
 
     # -- the speculative verify step ------------------------------------------
 
@@ -1758,122 +1824,127 @@ class ContinuousEngine:
         tail back: cache cursors via ``set_pos``, pool blocks via
         ``KVPool.truncate``.  A slot whose span cannot be hosted even at
         k = 0 is preempted (re-queued with produced tokens; the freed
-        blocks guarantee its lone re-admission succeeds)."""
+        blocks guarantee its lone re-admission succeeds).
+
+        Traced: ``prep`` (reservation, drafts, inputs), then the
+        ``verify`` span holding ``launch``/``sync``, then ``retire``."""
         L = self.spec_k + 1
-        ks: dict[int, int] = {}
-        run: list[int] = []
-        grew = False
-        pressure = False
-        for i in active:
-            st = self._slots[i]
-            remaining = st.req.max_new_tokens - len(st.produced)
-            headroom = self.max_len - int(self._pos[i]) - 1
-            # _spec_k_live <= spec_k: the adaptive-degradation cap
-            # (_note_spec_pressure); the verify SHAPE stays spec_k + 1
-            k_i = max(0, min(self._spec_k_live, remaining - 1, headroom))
-            nblk = int(self.pool.n_slot_blocks[i])
-            while not self.pool.extend(i, int(self._pos[i]) + k_i + 1):
-                pressure = True
-                if k_i == 0:
-                    k_i = -1
-                    break
-                k_i = 0
-            if k_i < 0:
-                self._preempt(i)
-                continue
-            grew |= int(self.pool.n_slot_blocks[i]) != nblk
-            ks[i] = k_i
-            run.append(i)
-        # note pressure BEFORE the empty-run early-return: a step whose
-        # every denied slot got preempted is maximal pressure, not none
-        self._note_spec_pressure(pressure)
-        if not run:
-            return
-        # writable span BEFORE the draft runs: tables are shared, so the
-        # draft's speculative writes must land in forked blocks too.
-        for i in run:
-            self.pool.ensure_writable(i, int(self._pos[i]),
-                                      int(self._pos[i]) + ks[i])
-        self._apply_cow()
-        if grew:
-            # only re-upload the table mirror when extend actually grew a
-            # row (most steps speculate within the blocks already mapped;
-            # stale trailing entries from last step's truncate sit beyond
-            # the validity bound, so reads through them are masked).
-            self._bt = jnp.asarray(self.pool.tables)
-        drafts = self.spec.propose(self, run, ks)
-        if self.faults is not None:
-            # draft-corruption seam: garbage drafts cost speculation
-            # efficiency only — verify rejects them, output is unchanged
-            drafts = {i: self.faults.corrupt_drafts(
-                self.steps + self.chunk_steps, d, self.cfg.vocab)
-                for i, d in drafts.items()}
-
-        toks = np.zeros((self.slots, L), np.int32)
-        lens = np.zeros(self.slots, np.int32)
-        for i in run:
-            d = [int(t) for t in drafts.get(i, [])][:ks[i]]
-            drafts[i] = d
-            toks[i, 0] = self._slots[i].cur_tok
-            toks[i, 1:1 + len(d)] = d
-            lens[i] = len(d) + 1
-        self._register_gemms(self.slots * L, self.slots * L)
-        t0 = time.perf_counter()
-        tok, self.caches = self._fns["verify_chunk"](
-            self.params, jnp.asarray(toks), self.caches, self._slot_ids,
-            self._bt, jnp.asarray(lens))
-        self._c_steps.inc()
-        self._s_decode.append(time.perf_counter())
-        self._chunks_since_decode = 0
-
-        tok_np = np.asarray(tok)
-        if self._tr.enabled:
-            self._tr.event("verify", step=self.steps + self.chunk_steps,
-                           ts=t0, dur=time.perf_counter() - t0,
-                           rows=len(run))
-        rejected = False
-        for i in run:
-            st = self._slots[i]
-            d = drafts[i]
-            emit: list[int] = []
-            j = 0
-            while True:
-                # emitting tok[j] is valid iff inputs 0..j were correct:
-                # input 0 is cur_tok (always), input j+1 is draft j —
-                # checked before advancing.  Budget/EOS stop emission.
-                t = int(tok_np[i, j])
-                emit.append(t)
-                if (t == st.req.eos or len(st.produced) + len(emit)
-                        >= st.req.max_new_tokens):
-                    break
-                if j < len(d) and d[j] == t:
-                    j += 1
+        tr = self._tr
+        with (tr.span("prep") if tr.enabled else NO_SPAN):
+            ks: dict[int, int] = {}
+            run: list[int] = []
+            grew = False
+            pressure = False
+            for i in active:
+                st = self._slots[i]
+                remaining = st.req.max_new_tokens - len(st.produced)
+                headroom = self.max_len - int(self._pos[i]) - 1
+                # _spec_k_live <= spec_k: the adaptive-degradation cap
+                # (_note_spec_pressure); the verify SHAPE stays spec_k + 1
+                k_i = max(0, min(self._spec_k_live, remaining - 1, headroom))
+                nblk = int(self.pool.n_slot_blocks[i])
+                while not self.pool.extend(i, int(self._pos[i]) + k_i + 1):
+                    pressure = True
+                    if k_i == 0:
+                        k_i = -1
+                        break
+                    k_i = 0
+                if k_i < 0:
+                    self._preempt(i)
                     continue
-                break
-            st.produced.extend(emit)
-            st.cur_tok = emit[-1]
-            self._pos[i] += len(emit)
-            rejected |= len(emit) < int(lens[i])
-            self._c_spec_emitted.inc(len(emit))
-            self._c_spec_drafted.inc(len(d))
-            self._c_spec_accepted.inc(len(emit) - 1)
-            self._c_spec_verifies.inc()
-        # KV rollback: cursors back to the accepted lengths, rejected
-        # tail blocks back to the pool (ref-respecting truncate).  Full
-        # acceptance everywhere means the cursors already sit at the
-        # accepted lengths (verify advanced them by exactly ``lens``), so
-        # the reset dispatches are skipped on that hot path.
-        if rejected:
-            self.caches = self._fns["set_pos"](self.caches,
-                                               jnp.asarray(self._pos))
-            self.spec.on_rollback(self, self._pos)
-        for i in run:
-            self.pool.truncate(i, int(self._pos[i]))
-            st = self._slots[i]
-            if (st.cur_tok == st.req.eos
-                    or len(st.produced) >= st.req.max_new_tokens
-                    or self._pos[i] >= self.max_len):
-                self._finish(i)
+                grew |= int(self.pool.n_slot_blocks[i]) != nblk
+                ks[i] = k_i
+                run.append(i)
+            # note pressure BEFORE the empty-run early-return: a step whose
+            # every denied slot got preempted is maximal pressure, not none
+            self._note_spec_pressure(pressure)
+            if not run:
+                return
+            # writable span BEFORE the draft runs: tables are shared, so the
+            # draft's speculative writes must land in forked blocks too.
+            for i in run:
+                self.pool.ensure_writable(i, int(self._pos[i]),
+                                          int(self._pos[i]) + ks[i])
+            self._apply_cow()
+            if grew:
+                # only re-upload the table mirror when extend actually grew a
+                # row (most steps speculate within the blocks already mapped;
+                # stale trailing entries from last step's truncate sit beyond
+                # the validity bound, so reads through them are masked).
+                self._bt = jnp.asarray(self.pool.tables)
+            drafts = self.spec.propose(self, run, ks)
+            if self.faults is not None:
+                # draft-corruption seam: garbage drafts cost speculation
+                # efficiency only — verify rejects them, output is unchanged
+                drafts = {i: self.faults.corrupt_drafts(
+                    self.steps + self.chunk_steps, d, self.cfg.vocab)
+                    for i, d in drafts.items()}
+
+            toks = np.zeros((self.slots, L), np.int32)
+            lens = np.zeros(self.slots, np.int32)
+            for i in run:
+                d = [int(t) for t in drafts.get(i, [])][:ks[i]]
+                drafts[i] = d
+                toks[i, 0] = self._slots[i].cur_tok
+                toks[i, 1:1 + len(d)] = d
+                lens[i] = len(d) + 1
+            self._register_gemms(self.slots * L, self.slots * L)
+        # the step index the verify event carries once it has run
+        with (tr.span("verify", step=self.steps + self.chunk_steps + 1,
+                      rows=len(run)) if tr.enabled else NO_SPAN):
+            with (tr.span("launch") if tr.enabled else NO_SPAN):
+                tok, self.caches = self._fns["verify_chunk"](
+                    self.params, jnp.asarray(toks), self.caches,
+                    self._slot_ids, self._bt, jnp.asarray(lens))
+            self._c_steps.inc()
+            self._s_decode.append(time.perf_counter())
+            self._chunks_since_decode = 0
+            with (tr.span("sync") if tr.enabled else NO_SPAN):
+                tok_np = np.asarray(tok)
+        with (tr.span("retire") if tr.enabled else NO_SPAN):
+            rejected = False
+            for i in run:
+                st = self._slots[i]
+                d = drafts[i]
+                emit: list[int] = []
+                j = 0
+                while True:
+                    # emitting tok[j] is valid iff inputs 0..j were correct:
+                    # input 0 is cur_tok (always), input j+1 is draft j —
+                    # checked before advancing.  Budget/EOS stop emission.
+                    t = int(tok_np[i, j])
+                    emit.append(t)
+                    if (t == st.req.eos or len(st.produced) + len(emit)
+                            >= st.req.max_new_tokens):
+                        break
+                    if j < len(d) and d[j] == t:
+                        j += 1
+                        continue
+                    break
+                st.produced.extend(emit)
+                st.cur_tok = emit[-1]
+                self._pos[i] += len(emit)
+                rejected |= len(emit) < int(lens[i])
+                self._c_spec_emitted.inc(len(emit))
+                self._c_spec_drafted.inc(len(d))
+                self._c_spec_accepted.inc(len(emit) - 1)
+                self._c_spec_verifies.inc()
+            # KV rollback: cursors back to the accepted lengths, rejected
+            # tail blocks back to the pool (ref-respecting truncate).  Full
+            # acceptance everywhere means the cursors already sit at the
+            # accepted lengths (verify advanced them by exactly ``lens``), so
+            # the reset dispatches are skipped on that hot path.
+            if rejected:
+                self.caches = self._fns["set_pos"](self.caches,
+                                                   jnp.asarray(self._pos))
+                self.spec.on_rollback(self, self._pos)
+            for i in run:
+                self.pool.truncate(i, int(self._pos[i]))
+                st = self._slots[i]
+                if (st.cur_tok == st.req.eos
+                        or len(st.produced) >= st.req.max_new_tokens
+                        or self._pos[i] >= self.max_len):
+                    self._finish(i)
 
     def avg_accept_len(self) -> float:
         """Mean tokens a slot emits per verify it takes part in (1.0 =

@@ -9,6 +9,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import time
 
 import numpy as np
 import jax
@@ -251,7 +252,27 @@ def test_preempt_resume_event_order(tiny):
     eng = ContinuousEngine(cfg, params, slots=4, max_len=160,
                            kv_blocks=20, policy="slo_preempt", audit=True,
                            telemetry=Telemetry.on())
-    eng.run([dataclasses.replace(r) for r in reqs])
+    # the three long requests take the whole pool (19 usable blocks) and
+    # decode before the deadline-carrying ones arrive, and those have
+    # waited past their risk point at the next step however fast the host
+    # is: serving them takes preemptions
+    for r in reqs[:3]:
+        eng.submit(dataclasses.replace(r))
+    for _ in range(20):
+        if all(s is not None and s.phase == "decode"
+               for s in eng._slots[:3]):
+            break
+        eng.step()
+    for r in reqs[3:]:
+        eng.submit(dataclasses.replace(r))
+    time.sleep(1e-3)
+    done = []
+    for _ in range(500):
+        if len(done) == len(reqs):
+            break
+        eng.step()
+        done += eng.drain_results()
+    assert len(done) == len(reqs)
     assert eng.preemptions > 0                 # overload really preempted
     n_preempt = 0
     for rid, evs in _events_by_rid(eng.obs.tracer).items():
