@@ -220,6 +220,13 @@ def check_kernels() -> str:
                                       v if sc else v.astype(f32), bt, lens,
                                       scale=hd ** -0.5, **sc)
         errs[name] = _rel_err(got, want)
+    # rows of length 0 read nothing and come back zero, also when every
+    # row is idle and the kernel's grid is empty
+    idle = PA.paged_decode_kernel(q, *kv_fp, bt, jnp.zeros_like(lens),
+                                  scale=hd ** -0.5)
+    if np.asarray(idle).any():
+        raise SmokeFailure("paged_decode: rows of length 0 are not zero")
+    _say("  paged_decode, every row of length 0: zero (ok)")
 
     # projections: QKV, down-projection and LM head, at decode rows
     # (one per slot) and prefill-chunk rows (slots x chunk)

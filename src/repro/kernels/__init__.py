@@ -6,9 +6,10 @@
   mpgemm       — fp GEMM with WS / IS / OS selectable block schedules (§5)
   quant_matmul — int8-weight serving path (GTA's native-precision fast case)
   paged_attention — paged-decode attention for the block-paged KV pool
-                 (scalar-prefetched block tables, online softmax; pure-JAX
-                 gather fallback off-TPU; gather-GEMM shapes registered
-                 with the paper-§5 ScheduleCache)
+                 (a run-time work list over each row's valid pages,
+                 several pages a step, online softmax; pure-JAX gather
+                 fallback off-TPU; gather-GEMM shapes registered with the
+                 paper-§5 ScheduleCache)
   ops          — public padded/jit'd wrappers + the GEMM execution layer;
                  block shapes chosen by the GTA scheduling bridge
                  (core.tiling)

@@ -14,6 +14,11 @@ Byte accounting (HBM traffic proxy):
   * every scan: carries + stacked ins/outs once per iteration;
   * elementwise/fusable ops are NOT counted (XLA fuses them) except
     reductions and gathers/scatters, counted as read-in + write-out.
+
+A reduction costs one FLOP per floating-point input element (integer
+ones are index bookkeeping).  A ``pallas_call`` costs its body times its
+grid steps; a grid sized at run time counts the ``grid_bound`` the call
+publishes in its metadata.
 This intentionally approximates a well-fused TPU program; DESIGN.md §6
 records the convention.
 """
@@ -23,6 +28,7 @@ from __future__ import annotations
 from collections.abc import Callable
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 _FUSABLE = {
@@ -141,12 +147,20 @@ def _jaxpr_cost(jaxpr) -> Cost:
             sub = eqn.params.get("jaxpr")
             gm = eqn.params.get("grid_mapping")
             steps = 1
+            dynamic = False
             if gm is not None:
                 for g in getattr(gm, "grid", ()):
                     try:
                         steps *= int(g)
                     except (TypeError, ValueError):
-                        pass        # symbolic grid dim: count once
+                        dynamic = True
+            if dynamic:
+                # a grid sized at run time: the kernel may publish the
+                # most steps its run-time dims can make as the metadata
+                # key "grid_bound"; without it, count them once
+                bound = (eqn.params.get("metadata") or {}).get("grid_bound")
+                if bound is not None:
+                    steps *= int(bound)
             if sub is not None:
                 inner = sub.jaxpr if hasattr(sub, "jaxpr") else sub
                 total += _jaxpr_cost(inner).scaled(float(steps))
@@ -157,9 +171,13 @@ def _jaxpr_cost(jaxpr) -> Cost:
             total += Cost(0.0, float(byts))
         elif prim in ("reduce_sum", "reduce_max", "reduce_min",
                       "reduce_prod", "cumsum", "argmax", "argmin"):
-            total += Cost(float(_numel(eqn.invars[0].aval)),
-                          _bytes(eqn.invars[0].aval)
-                          + _bytes(eqn.outvars[0].aval))
+            # one op per input element, counted as FLOPs only over floats:
+            # integer reductions are index bookkeeping
+            a = eqn.invars[0].aval
+            flops = _numel(a) if jnp.issubdtype(a.dtype, jnp.floating) \
+                else 0
+            total += Cost(float(flops),
+                          _bytes(a) + _bytes(eqn.outvars[0].aval))
         elif prim in ("gather", "scatter", "scatter-add", "scatter_add",
                       "dynamic_slice", "dynamic_update_slice", "sort",
                       "take_along_axis"):

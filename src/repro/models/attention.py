@@ -350,7 +350,8 @@ def gqa_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
     — the Pallas paged-decode kernel on TPU, the pure-JAX gather fallback
     elsewhere.  ``pos_advance`` (B,) overrides the cache-pos increment
     (chunked ragged prefill advances by each row's REAL token count, not
-    the padded chunk length)."""
+    the padded chunk length); at decode, a row whose advance is 0 is not
+    decoding and attends nothing."""
     B, S, _ = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     G = H // KV
@@ -387,10 +388,18 @@ def gqa_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
             new_cache = {"k": ck, "v": cv, "pos": cache["pos"] + adv}
         kv_valid = cache["pos"] + adv
         if S == 1:
+            # decode: the paged-decode kernel walks each row's valid pages
+            # only.  Rows with pos_advance 0 are not decoding this step
+            # (their token is discarded) but keep their last request's
+            # cursor: they attend nothing, so their stale length costs no
+            # DMA and no compute.
             from repro.kernels import paged_attention as PA
+            lens = jnp.atleast_1d(kv_valid)
+            if pos_advance is not None:
+                lens = jnp.where(adv > 0, lens, 0)
             out = PA.decode_attention(
                 q.reshape(B, KV, G, hd), ck, cv, block_table,
-                jnp.atleast_1d(kv_valid), scale=scale, window=window,
+                lens, scale=scale, window=window,
                 logit_cap=cfg.attn_logit_softcap,
                 k_scale=cks, v_scale=cvs)
             out = out.reshape(B, 1, H * hd)

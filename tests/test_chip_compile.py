@@ -5,8 +5,10 @@ for a described ``v5e:2x2`` topology, and refuses what the chip would
 (tiles off the (8, 128) layout, VMEM overruns) — which interpret mode
 cannot show.  Shapes are qwen2-0.5b's full widths: hd 64, 2 KV heads with
 7 query heads each, 16-token blocks, d_model 896, d_ff 4864, vocab 151936,
-8 slots, 32-token prefill chunks.  Nothing runs, so these say nothing
-about numerics (``chip_smoke.py`` checks those on the chip).
+8 slots, 32-token prefill chunks; the paged-decode kernel also at the
+benchmark cell's geometry and at qwen1.5-4b's widths.  Nothing runs, so
+these say nothing about numerics (``chip_smoke.py`` checks those on the
+chip).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and a test worker that fails
@@ -61,19 +63,35 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
-def test_paged_decode_kernel_compiles(one_chip, kv_dtype):
-    pool = (NUM_BLOCKS, BLOCK, KV, HD)
-    args = [_sds(one_chip, (SLOTS, KV, G, HD), jnp.bfloat16),
+# (slots, table width, pool blocks, KV, G, hd): the 8-slot geometry above;
+# the benchmark's qwen2-0.5b.chat cell (16 slots, 4096 context, 3073
+# blocks); qwen1.5-4b's widths, which share the kernel
+SERVED = (SLOTS, 1024 // BLOCK, NUM_BLOCKS, KV, G, HD)
+CELL = (16, 4096 // BLOCK, 3073, KV, G, HD)
+QWEN15_4B = (16, 4096 // BLOCK, 448, 20, 1, 128)
+
+
+@pytest.mark.parametrize("kv_dtype,geom", [
+    pytest.param("bfloat16", SERVED, id="bfloat16"),
+    pytest.param("int8", SERVED, id="int8"),
+    pytest.param("bfloat16", CELL, id="cell-bfloat16"),
+    pytest.param("int8", CELL, id="cell-int8"),
+    pytest.param("bfloat16", QWEN15_4B, id="qwen1.5-4b-bfloat16"),
+    pytest.param("int8", QWEN15_4B, id="qwen1.5-4b-int8"),
+])
+def test_paged_decode_kernel_compiles(one_chip, kv_dtype, geom):
+    slots, nbs, nb, kv, g, hd = geom
+    pool = (nb, BLOCK, kv, hd)
+    args = [_sds(one_chip, (slots, kv, g, hd), jnp.bfloat16),
             _sds(one_chip, pool, kv_dtype), _sds(one_chip, pool, kv_dtype),
-            _sds(one_chip, (SLOTS, 1024 // BLOCK), jnp.int32),
-            _sds(one_chip, (SLOTS,), jnp.int32)]
+            _sds(one_chip, (slots, nbs), jnp.int32),
+            _sds(one_chip, (slots,), jnp.int32)]
     if kv_dtype == "int8":
         args += [_sds(one_chip, pool[:3], jnp.float32)] * 2
 
     def step(q, k, v, bt, lens, *scales):
         sc = dict(zip(("k_scale", "v_scale"), scales))
-        return PA.paged_decode_kernel(q, k, v, bt, lens, scale=HD ** -0.5,
+        return PA.paged_decode_kernel(q, k, v, bt, lens, scale=hd ** -0.5,
                                       interpret=False, **sc)
 
     compiled = jax.jit(step).lower(*args).compile()

@@ -93,3 +93,31 @@ ENTRY %main.1 (p0: f32[16,16]) -> f32[16,16] {
     coll = mod.collective_bytes()
     want = 2 * 16 * 16 * 4 * 7 / 8  # 2*size*(n-1)/n, n=8
     assert coll["per_device_bytes"] == pytest.approx(want)
+
+
+@pytest.mark.parametrize("dtype,flops", [(jnp.float32, 4 * 6),
+                                         (jnp.int32, 0)])
+def test_jaxpr_cost_counts_float_reductions_only(dtype, flops):
+    x = jnp.zeros((4, 6), dtype)
+    c = step_cost(lambda x: jnp.sum(x, axis=1), x)
+    assert c["flops"] == flops
+    assert c["bytes"] > 0
+
+
+def test_jaxpr_cost_scales_a_run_time_grid_by_its_bound():
+    """The paged-decode kernel's grid is sized at run time; the cost model
+    counts its body once per item of the longest work list the call
+    publishes (every row at the full table): the two matmuls and the
+    softmax's max and sum over each step's tokens."""
+    from repro.kernels import paged_attention as PA
+    B, KV, G, hd, bs, nbs = 2, 2, 3, 16, 8, 40
+    P = PA.pages_per_step(bs, KV, hd, 4, nbs)
+    items, T = B * -(-nbs // P), P * bs
+    q = jnp.zeros((B, KV, G, hd), jnp.float32)
+    pool = jnp.zeros((B * nbs + 1, bs, KV, hd), jnp.float32)
+    bt = jnp.arange(1, B * nbs + 1, dtype=jnp.int32).reshape(B, nbs)
+    lens = jnp.full((B,), nbs * bs, jnp.int32)
+    c = step_cost(lambda *a: PA.paged_decode_kernel(
+        *a, scale=0.25, interpret=True), q, pool, pool, bt, lens)
+    assert items > B
+    assert c["flops"] == items * KV * G * T * (2 * hd + 2 * hd + 2)

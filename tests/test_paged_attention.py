@@ -27,17 +27,131 @@ def _pool_setup(B=3, KV=2, G=4, hd=32, nb=12, bs=8, nbs=6,
     return q, k, v, bt, jnp.asarray(lens, jnp.int32)
 
 
-@pytest.mark.parametrize("window,cap", [(None, None), (7, None),
-                                        (None, 30.0), (9, 50.0)])
-def test_kernel_matches_gather_fallback(window, cap):
-    q, k, v, bt, lens = _pool_setup()
+def _quantize(pool):
+    """int8 payload + per-(position, kv-head) scale, as the pool stores it."""
+    amax = jnp.max(jnp.abs(pool), axis=-1)
+    sc = jnp.where(amax > 0, amax / 127.0, 1.0)
+    return jnp.round(pool / sc[..., None]).astype(jnp.int8), sc
+
+
+# bs 8 makes a grid step P = 16 pages (128 tokens) over a 40-page table:
+# lengths 0 (no DMA, zero tile), 1, exactly P*bs, one past it, the full
+# table
+RAGGED = dict(B=5, nbs=40, nb=64, lens=(0, 1, 128, 129, 320))
+
+
+@pytest.mark.parametrize("window,cap,geom,quant", [
+    pytest.param(None, None, {}, False, id="None-None"),
+    pytest.param(7, None, {}, False, id="7-None"),
+    pytest.param(None, 30.0, {}, False, id="None-30.0"),
+    pytest.param(9, 50.0, {}, False, id="9-50.0"),
+    pytest.param(None, None, RAGGED, False, id="ragged"),
+    pytest.param(150, None, RAGGED, False, id="ragged-window"),
+    pytest.param(None, 30.0, RAGGED, False, id="ragged-cap"),
+    pytest.param(None, None, RAGGED, True, id="ragged-int8"),
+    pytest.param(40, 50.0, RAGGED, True, id="ragged-int8-window-cap"),
+    pytest.param(None, None, dict(lens=(0, 0, 0)), False, id="all-idle"),
+])
+def test_kernel_matches_gather_fallback(window, cap, geom, quant):
+    q, k, v, bt, lens = _pool_setup(**geom)
+    sc = {}
+    if quant:
+        (k, ks), (v, vs) = _quantize(k), _quantize(v)
+        sc = dict(k_scale=ks, v_scale=vs)
     ref = PA.gather_fallback(q, k, v, bt, lens, scale=0.17,
-                             window=window, logit_cap=cap)
+                             window=window, logit_cap=cap, **sc)
     ker = PA.paged_decode_kernel(q, k, v, bt, lens, scale=0.17,
                                  window=window, logit_cap=cap,
-                                 interpret=True)
+                                 interpret=True, **sc)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+    # a row of length 0 attends nothing: a zero tile on both paths
+    assert (np.asarray(ker)[np.asarray(lens) == 0] == 0).all()
+
+
+@pytest.mark.parametrize("window", [None, 20], ids=["full", "window"])
+def test_work_list_reads_only_valid_pages(window):
+    """The grid visits each row's valid pages only, P at a time, rows in
+    order; a row of length 0 gets no item, and a step's spare page
+    operands repeat the row's last page instead of reading past it."""
+    bs, P = 8, 4
+    bt = jnp.arange(1, 31, dtype=jnp.int32).reshape(3, 10)
+    lens = jnp.asarray([0, 17, 80], jnp.int32)
+    n, row, step, blk, first, steps = PA._work_list(
+        bt, lens, bs=bs, pages=P, window=window)
+    n = int(n)
+    blk = np.asarray(blk).reshape(-1, P)[:n]
+    if window is None:
+        want = [(1, 0, [0, 1, 2, 2]), (2, 0, [0, 1, 2, 3]),
+                (2, 1, [4, 5, 6, 7]), (2, 2, [8, 9, 9, 9])]
+    else:       # row 2's window starts at position 60, in page 7
+        want = [(1, 0, [0, 1, 2, 2]), (2, 0, [7, 8, 9, 9])]
+    assert n == len(want)
+    for i, (r, st, pages) in enumerate(want):
+        assert (int(row[i]), int(step[i])) == (r, st)
+        np.testing.assert_array_equal(blk[i], np.asarray(bt)[r, pages])
+    assert int(steps[0]) == 0
+
+
+def test_pages_per_step_from_shapes():
+    """P covers >= 128 tokens, never more pages than the table holds, and
+    shrinks only when a step's padded pages would outgrow VMEM."""
+    assert PA.pages_per_step(16, 2, 64, 2, 256) == 8
+    assert PA.pages_per_step(8, 2, 32, 4, 40) == 16
+    assert PA.pages_per_step(16, 2, 64, 2, 4) == 4
+    assert PA.pages_per_step(16, 20, 128, 2, 256) == 8
+    assert PA.pages_per_step(256, 64, 512, 4, 256) == 1
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_idle_rows_attend_nothing_at_the_call_site(monkeypatch, quant):
+    """At decode, rows with pos_advance 0 reach the kernel with length 0
+    whatever cursor they kept, so a stale cursor leaves the decoding rows'
+    outputs bit-identical; without pos_advance every row attends pos + 1."""
+    import dataclasses
+    from repro import configs as CONFIGS
+    from repro.models.config import BlockKind
+    cfg = CONFIGS.get("qwen2_0_5b").scaled_down()
+    cfg = dataclasses.replace(cfg, quant_serving=quant)
+    D, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    B, bs, nbs = 4, 8, 8
+    p = {"wq": _rand(D, H * hd) * D ** -0.5,
+         "wk": _rand(D, KV * hd) * D ** -0.5,
+         "wv": _rand(D, KV * hd) * D ** -0.5,
+         "wo": _rand(H * hd, D) * (H * hd) ** -0.5}
+    cache = A.make_paged_kv_cache(cfg, 1 + B * nbs, bs, jnp.float32)
+    for name in ("k", "v"):
+        pool = _rand(*cache[name].shape)
+        if quant:
+            cache[name], cache[f"{name}_scale"] = _quantize(pool)
+        else:
+            cache[name] = pool
+    bt = jnp.asarray(1 + RNG.permutation(B * nbs).reshape(B, nbs),
+                     jnp.int32)
+    x = _rand(B, 1, D)
+    adv = jnp.asarray([1, 0, 1, 0], jnp.int32)
+
+    seen, dispatch = [], PA.decode_attention
+
+    def kernel(*args, **kw):
+        seen.append(np.asarray(args[4]))
+        return dispatch(*args, use_kernel=True, interpret=True, **kw)
+    monkeypatch.setattr(PA, "decode_attention", kernel)
+
+    def run(pos, pos_advance):
+        c = dict(cache, pos=jnp.asarray(pos, jnp.int32))
+        out, _ = A.gqa_attention(p, x, cfg, kind=BlockKind.ATTN,
+                                 pos_offset=c["pos"], cache=c,
+                                 block_table=bt, pos_advance=pos_advance)
+        return np.asarray(out)
+
+    stale = run([37, 5, 50, 60], adv)
+    fresh = run([37, 0, 50, 0], adv)
+    run([37, 5, 50, 60], None)
+    np.testing.assert_array_equal(seen[0], [38, 0, 51, 0])
+    np.testing.assert_array_equal(seen[1], [38, 0, 51, 0])
+    np.testing.assert_array_equal(seen[2], [38, 6, 51, 61])
+    np.testing.assert_array_equal(stale[adv == 1], fresh[adv == 1])
 
 
 def test_decode_attention_dispatch_off_tpu_is_fallback():
